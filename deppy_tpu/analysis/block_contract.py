@@ -10,9 +10,9 @@ padding waste (ROADMAP item 3's "a 64-clause problem pays the
 declared size class, against the kernel/driver sources:
 
   * ``smem-budget`` — per ``pallas_call`` in the fused search module,
-    the number of whole-column ``(B, 1)`` SMEM specs
+    the number of whole-vector ``(B,)`` SMEM specs
     (``_smem_scalars``) x ``B=4096`` (the widest lane width
-    ``scripts/lane_probe.py`` probes, the ``test_mosaic_lowering``
+    ``scripts/lane_probe.py`` probes, the ``test_tpu_compile``
     regression anchor) x 4 bytes must stay under
     :data:`SMEM_BUDGET_BYTES`, and the column count under
     :data:`MAX_SMEM_COLS`;
@@ -71,7 +71,7 @@ from .. import size_classes as _shared
 # words; OCC = the watched bank's occurrence cap.
 SIZE_CLASSES: Dict[str, Dict[str, int]] = _shared.SIZE_CLASSES
 # Widest per-problem batch the SMEM scalar columns are probed at
-# (scripts/lane_probe.py; tests/test_mosaic_lowering.py B=4096 anchor).
+# (scripts/lane_probe.py; tests/test_tpu_compile.py B=4096 anchor).
 SMEM_ANCHOR_B = 4096
 SMEM_BUDGET_BYTES = 128 * 1024
 MAX_SMEM_COLS = 8
@@ -188,11 +188,11 @@ class BlockContractChecker(Checker):
                         out, sf, call.lineno, "smem-budget",
                         f"{fn.name}:{n_cols}",
                         f"pallas_call in `{fn.name}` maps {n_cols} "
-                        f"whole-column (B, 1) scalar specs into SMEM — "
+                        f"whole-vector (B,) scalar specs into SMEM — "
                         f"{col_bytes} bytes at the probed B="
                         f"{SMEM_ANCHOR_B} anchor (budget "
                         f"{SMEM_BUDGET_BYTES}, max {MAX_SMEM_COLS} "
-                        f"columns); see tests/test_mosaic_lowering.py")
+                        f"columns); see tests/test_tpu_compile.py")
         self._check_per_row_smem(out, sf)
 
     def _check_per_row_smem(self, out: List[Finding],

@@ -8,5 +8,5 @@ rebuild's measured equivalents:
   * :mod:`deppy_tpu.benchmarks.headline` — the driver-facing headline
     metric (batched catalog resolutions/sec, device vs serial host);
   * :mod:`deppy_tpu.benchmarks.suite` — all five BASELINE.json workload
-    configs, host vs device, for BASELINE.md.
+    configs, host vs device.
 """

@@ -24,10 +24,9 @@ def probe_wall_s() -> float:
     """Wall-clock seconds for the first touch of the JAX backend (PJRT
     init + device enumeration), measured once per process and cached.
 
-    BENCH_r01–r05 carried multi-minute backend-probe/init stalls that
-    were invisible in the emitted JSON (the retries happened before any
-    timed section); recording the first-touch wall in every BENCH record
-    makes them attributable without a rerun.  Call this BEFORE anything
+    Backend-probe/init stalls are invisible in the emitted JSON (they
+    happen before any timed section); recording the first-touch wall in
+    every BENCH record makes them attributable without a rerun.  Call this BEFORE anything
     else touches the backend (``jax.default_backend()``,
     ``jax.devices()``) or the measurement reads ~0."""
     global _PROBE_WALL_S

@@ -75,8 +75,8 @@ def run(n_problems: int = 4096, length: int = 48, host_sample: int = 24,
         "host_rate_live": round(1.0 / m["host_s_per_problem"], 1),
         "host_rate_used": round(1.0 / host_s, 1),
         # Startup attribution (ISSUE 4 satellite): backend first-touch
-        # wall and the untimed compile warm-up — the BENCH_r01-r05
-        # multi-minute probe/retry stalls were invisible without these.
+        # wall and the untimed compile warm-up — probe/retry stalls
+        # are invisible without these.
         "probe_wall_s": round(probe_s, 3),
         "warmup_seconds": round(m["warmup_seconds"], 3),
         # Host-path pool size (ISSUE 5 satellite; 0 = inline serial).

@@ -11,8 +11,7 @@ kernel's VMEM caps (C ≤ 8192, Wv ≤ 128; see pallas_bcp.py) — and
 measures ``bits`` vs ``pallas`` on it.
 
 Run on TPU: ``python -m deppy_tpu.benchmarks.pallas_case``.
-Prints one JSON line per impl and a final comparison line; feeds the
-"earn the Pallas kernel's keep" row of BASELINE.md.
+Prints one JSON line per impl and a final comparison line.
 """
 
 from __future__ import annotations

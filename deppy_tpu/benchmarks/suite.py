@@ -3,7 +3,7 @@
 The reference publishes no numbers (SURVEY.md §6), so this suite produces
 the rebuild's own: for each config, a sampled serial host-engine baseline
 (the stand-in for the reference's single-threaded gini solver) and the
-batched device rate.  Results feed BASELINE.md.
+batched device rate.
 
 Run: ``python -m deppy_tpu.benchmarks.suite [--quick] [--out FILE]``.
 Prints one JSON object per config on stdout (one line each), detail on

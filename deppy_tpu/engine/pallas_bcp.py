@@ -81,8 +81,8 @@ def bcp_fixpoint(pos, neg, mem, card_active, card_n2, min_bits, min_w, t0, f0,
     """Run BCP to fixpoint on bitplanes.  Shapes as in
     :func:`deppy_tpu.engine.core.round_planes` (``card_active`` is the
     precomputed [NA, 1] row-activity mask); returns (conflict, t, f).
-    Interprets on non-TPU backends so the same code path is testable on the
-    CPU mesh used by the test suite."""
+    Interprets on the CPU backend (:func:`core.pallas_interpret`) so the
+    same code path is testable on the CPU mesh used by the test suite."""
     Wv = pos.shape[1]
     minw2 = jnp.full((1, 1), min_w, jnp.int32)
     en2 = jnp.full((1, 1), enabled, jnp.int32)
@@ -105,6 +105,6 @@ def bcp_fixpoint(pos, neg, mem, card_active, card_n2, min_bits, min_w, t0, f0,
             vmem,
             vmem,
         ),
-        interpret=jax.default_backend() != "tpu",
+        interpret=core.pallas_interpret(),
     )(minw2, en2, pos, neg, mem, act, card_n2, min_bits, t0, f0)
     return conf[0, 0] != 0, t, f

@@ -39,8 +39,8 @@ with the bits path holds bit-for-bit — pinned by
 tests/test_pallas_blockwise.py's differential suite.
 
 Like every device bet in this tree the impl is opt-in
-(``DEPPY_TPU_BCP=blockwise``) until a real-chip measurement lands in
-BASELINE.md; ``benchmarks/pallas_case.py --impl blockwise`` builds the
+(``DEPPY_TPU_BCP=blockwise``) until a chip measurement shows it
+winning; ``benchmarks/pallas_case.py --impl blockwise`` builds the
 2-4× VMEM case.
 """
 
@@ -139,7 +139,7 @@ def _sweep(pos, neg, mem, card_active, card_n2, min_bits, min_w, t, f,
             jax.ShapeDtypeStruct((1, Wv), jnp.int32),
             jax.ShapeDtypeStruct((1, Wv), jnp.int32),
         ),
-        interpret=jax.default_backend() != "tpu",
+        interpret=core.pallas_interpret(),
     )(minw2, en2, pos, neg, mem, act, card_n2, min_bits, t, f)
     return conf[0, 0] != 0, t, f
 
@@ -162,7 +162,7 @@ def bcp_fixpoint(pos, neg, mem, card_active, card_n2, min_bits, min_w,
     # Interpret mode has no such constraint and keeps the exact br so
     # the tiny-block differential tests still exercise multi-block
     # sweeps (cross-block conflict/forcing propagation).
-    if jax.default_backend() == "tpu":
+    if not core.pallas_interpret():
         br = max(8 * ((br + 7) // 8), 8)
     pad = (-C) % br
     if pad:

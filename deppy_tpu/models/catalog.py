@@ -167,9 +167,7 @@ def giant_pinned_conflict(
     the cluster-wide "two mandatory operators are incompatible" failure
     at full catalog scale.  The answer is a 3-constraint core buried in
     thousands of irrelevant constraints: the workload that exercises
-    host-routed core extraction (engine.driver.HOST_CORE_NCONS) and,
-    historically, the long-device-program worker crash it guards against
-    (BASELINE.md round-3 notes)."""
+    host-routed core extraction (engine.driver.HOST_CORE_NCONS)."""
     out = list(operatorhub_catalog(n_packages, versions_per_package, seed))
     out.append(Variable("pin-a", (mandatory(), conflict("pin-b"))))
     out.append(Variable("pin-b", (mandatory(),)))

@@ -19,7 +19,6 @@ orthogonal parallelism axes:
 Both scale to multi-host DCN fleets via ``jax.distributed`` initialization.
 """
 
-from ._compat import resolve_shard_map, shard_map
 from .clause_shard import clause_mesh, solve_one_sharded, solve_sharded
 from .mesh import (BATCH_AXIS, batch_sharding, default_mesh,
                    initialize_distributed, mesh_devices_from_env,
@@ -28,7 +27,6 @@ from .mesh import (BATCH_AXIS, batch_sharding, default_mesh,
 __all__ = [
     "BATCH_AXIS", "batch_sharding", "default_mesh",
     "initialize_distributed", "mesh_devices_from_env",
-    "replicated_sharding", "resolve_shard_map", "serving_mesh",
-    "shard_batch", "shard_map",
+    "replicated_sharding", "serving_mesh", "shard_batch",
     "clause_mesh", "solve_one_sharded", "solve_sharded",
 ]

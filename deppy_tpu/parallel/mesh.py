@@ -22,9 +22,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from ..utils.platform_env import assert_env_platform
 
 # ``default_mesh()``/``clause_mesh()`` are often a user process's first
-# backend query; make ``JAX_PLATFORMS=cpu`` limit plugin discovery before
-# it happens (a wedged accelerator plugin hangs init otherwise — see
-# platform_env.assert_env_platform).
+# backend query; make ``JAX_PLATFORMS`` decide which backends it
+# initializes (see platform_env.assert_env_platform).
 assert_env_platform()
 
 BATCH_AXIS = "batch"
@@ -72,10 +71,8 @@ def serving_mesh(n_devices: Optional[int] = None) -> Optional[Mesh]:
     (ISSUE 6), or None when mesh serving is off.  ``n_devices`` -1 (or
     ``DEPPY_TPU_MESH_DEVICES=all``) takes every local device; a count
     above the platform's device total clamps with a warning rather than
-    failing serving.  Callers resolve this lazily — only after the
-    backend probe said the device platform is usable — because
-    enumerating devices is exactly the call that hangs on a wedged
-    accelerator plugin (see assert_env_platform)."""
+    failing serving.  Callers resolve this lazily, on the first device
+    dispatch."""
     if n_devices is None:
         n_devices = mesh_devices_from_env()
     if n_devices is None:
@@ -145,23 +142,13 @@ def initialize_distributed(**kwargs) -> None:
     back to single-host there would make every host redundantly solve the
     full batch."""
     if not kwargs:
-        try:
-            from jax._src.clusters import ClusterEnv
+        from jax._src.clusters import ClusterEnv
 
-            detected = any(c.is_env_present() for c in ClusterEnv._cluster_types)
-        # deppy: lint-ok[exception-hygiene] probe fallback: absence of a cluster env IS the verdict
-        except Exception:  # private API moved: assume plain single-host
-            detected = False
-        if not detected:
+        if not any(c.is_env_present() for c in ClusterEnv._cluster_types):
             return  # plain single-process launch: nothing to initialize
     if (os.environ.get("JAX_PLATFORMS") or "").strip() == "cpu":
         # Cross-process collectives on XLA:CPU need an explicit transport
         # (TPU fleets ride ICI/DCN natively); without this the first
-        # collective hangs.  Gloo ships with jaxlib; config name guarded
-        # so a jax that drops the option degrades to its own default.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        # deppy: lint-ok[exception-hygiene] optional config on older jax; initialize() below fails loud
-        except Exception:
-            pass
+        # collective hangs.
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(**kwargs)

@@ -39,9 +39,6 @@ Design points, in the order the issue states them:
     resolution degrades ``auto`` to the host engine and the queue keeps
     draining (host-only mode).
 
-The dispatch loop resolves the backend with ``block=False``: it must
-never stall every queued request behind a first-use 75s engine probe
-(the service pre-warm owns that probe).
 """
 
 from __future__ import annotations
@@ -630,9 +627,7 @@ class Scheduler:
         # micro-batch over a jax mesh.  ``mesh`` pins one explicitly
         # (tests, library callers); otherwise ``mesh_devices`` (or the
         # DEPPY_TPU_MESH_DEVICES env mirror) sizes one LAZILY on the
-        # first device dispatch — enumerating devices up front is
-        # exactly the call that hangs on a wedged accelerator plugin,
-        # and the scheduler must never probe (see _prewarm_backend).
+        # first device dispatch.
         self._mesh = mesh
         self._mesh_devices = mesh_devices
         self._mesh_resolved = mesh is not None
@@ -865,28 +860,6 @@ class Scheduler:
             self._thread = threading.Thread(
                 target=self._loop, name="deppy-sched", daemon=True)
             self._thread.start()
-        self._prewarm_backend()
-
-    def _prewarm_backend(self) -> None:
-        """The dispatch loop resolves the backend with ``block=False``
-        (it must never stall the queue behind the 75s engine probe), so
-        ``auto`` answers "host" until SOMETHING establishes the
-        usability verdict.  The service's startup pre-warm owns that on
-        the served path; a standalone Scheduler (library callers) would
-        otherwise route host forever on a device platform — kick one
-        background probe here so auto routing upgrades once it lands."""
-        import os
-
-        if self.backend != "auto":
-            return
-        from ..sat import solver as sat_solver
-
-        if (sat_solver._ENGINE_USABLE is not None
-                or (os.environ.get("JAX_PLATFORMS") or "").strip()
-                == "cpu"):
-            return
-        threading.Thread(target=lambda: sat_solver.resolve_backend("auto"),
-                         name="deppy-sched-prewarm", daemon=True).start()
 
     def stop(self, timeout: float = 10.0) -> None:
         """Stop the loop; queued LIVE groups drain (dispatch) first so
@@ -1801,7 +1774,7 @@ class Scheduler:
         deadlines = [lane.deadline for lane in live]
         if all(d is not None for d in deadlines):
             scope = max(deadlines, key=lambda d: d.remaining())
-        backend = resolve_backend(self.backend, block=False)
+        backend = resolve_backend(self.backend)
         if (self.backend == "auto" and backend == "host"
                 and faults.default_breaker().blocks_device()):
             # ISSUE 14 satellite: this flush is a breaker-open host
@@ -2051,7 +2024,7 @@ class Scheduler:
     def _kick_reprobe(self) -> None:
         """Start the background re-probe loop (once) after a
         breaker-open host drain.  The loop waits out the breaker
-        cooldown, then runs the killable subprocess engine probe OFF
+        cooldown, then runs the engine probe OFF
         the serving path — a success resets the breaker and replaces
         the ``auto`` verdict (``sat.solver.reprobe_engine``), so
         routing upgrades without risking a live dispatch on the
@@ -2080,8 +2053,8 @@ class Scheduler:
         # re-learning the failure that opened it — whatever the
         # configured interval); FAILED probes retry on the full
         # DEPPY_TPU_REPROBE interval — remaining_s() is 0 once the
-        # cooldown lapses, and a 75s subprocess probe must not hot-loop
-        # against a dead accelerator.
+        # cooldown lapses, and the probe must not hot-loop against a
+        # dead accelerator.
         delay = max(faults.default_breaker().remaining_s(), 1.0)
         while True:
             if self._reprobe_stop.wait(delay):
@@ -2100,11 +2073,7 @@ class Scheduler:
                 # already knows the answer.
                 delay = max(faults.default_breaker().remaining_s(), 1.0)
                 continue
-            try:
-                ok = sat_solver.reprobe_engine()
-            # deppy: lint-ok[exception-hygiene] probe failure = not recovered; retried next tick
-            except Exception:
-                ok = False
+            ok = sat_solver.reprobe_engine()  # a verdict; never raises
             c_reprobes.inc(label="upgraded" if ok else "failed")
             if ok:
                 telemetry.default_registry().event(

@@ -763,36 +763,26 @@ class Server:
             t.start()
             self._threads.append(t)
         if self.backend == "auto":
-            # Pre-warm the auto-backend usability verdict: against a
-            # crashed TPU worker the probe takes its full timeout (75s)
-            # before falling back to host.  The verdict is process-cached
-            # and probing is serialized (solver._ENGINE_USABLE_LOCK), so a
-            # request landing mid-probe waits on the SHARED probe — worst
-            # case the remaining probe window, never a duplicate one —
-            # and every request after the verdict routes instantly.
+            # Pre-warm the auto-backend usability verdict (the engine
+            # import and backend init) off the request path.  The verdict
+            # is process-cached and probing is serialized
+            # (solver._ENGINE_USABLE_LOCK), so a request landing mid-probe
+            # waits on the SHARED probe.
             #
             # If the verdict comes back negative, keep re-probing on an
-            # interval (DEPPY_TPU_REPROBE seconds, 0 disables): a service
-            # that boots during a worker outage upgrades auto routing to
-            # the tensor engine when the worker recovers, instead of
-            # serving from the host engine for the rest of its life.
+            # interval (DEPPY_TPU_REPROBE seconds, 0 disables), so auto
+            # routing upgrades to the tensor engine once the backend
+            # answers.
             def _prewarm():
                 from .sat import solver as sat_solver
 
-                try:
-                    if sat_solver.resolve_backend("auto") == "tpu":
-                        return
-                # deppy: lint-ok[exception-hygiene] request-path resolution surfaces the real error
-                except Exception:
-                    pass  # request-path resolution will surface errors
+                # The probe returns a verdict and never raises.
+                if sat_solver.resolve_backend("auto") == "tpu":
+                    return
                 while self._reprobe_s > 0 and not self._stop.wait(
                         self._reprobe_s):
-                    try:
-                        if sat_solver.reprobe_engine():
-                            return
-                    # deppy: lint-ok[exception-hygiene] transient reprobe failure; next tick retries
-                    except Exception:
-                        continue  # transient; keep trying next tick
+                    if sat_solver.reprobe_engine():
+                        return
 
             threading.Thread(target=_prewarm, daemon=True).start()
         if self.fleet_router:

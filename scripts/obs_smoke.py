@@ -132,9 +132,11 @@ def boot_replica(name, port, workdir, router_port=None, baseline=None,
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["DEPPY_TPU_OBS_DRIFT_BAND"] = str(DRIFT_BAND)
-    # Shared persistent jit cache: replicas after the first reuse the
+    # Shared persistent jit cache at a fixed path (the path is part of
+    # the cache key): replicas after the first, and later runs, reuse the
     # baseline run's compile instead of paying ~seconds each.
-    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(workdir, "jaxcache")
+    env.setdefault("JAX_COMPILATION_CACHE_DIR",
+                   os.path.join(REPO, ".jax_cache"))
     log = open(os.path.join(workdir, f"{name}.log"), "w")
     proc = subprocess.Popen(argv, cwd=REPO, env=env,
                             stdout=log, stderr=subprocess.STDOUT)

@@ -93,7 +93,6 @@ def main() -> None:
                   "DEPPY_TPU_SEARCH", "DEPPY_TPU_BCP"):
             env.pop(k, None)
         env["DEPPY_TPU_SPEC_CORE"] = value
-        env.setdefault("DEPPY_TPU_COMPILE_CACHE", "on")
         rec = run_stage({"variant": variant,
                          "packages": a.packages, "versions": a.versions},
                         [sys.executable, "-c", src], env,

@@ -201,7 +201,6 @@ def main() -> None:
     env_off = dict(os.environ)
     env_off["DEPPY_TPU_COMPILE_CACHE"] = "off"
     env_on = dict(os.environ)
-    env_on["DEPPY_TPU_COMPILE_CACHE"] = "on"
     py = sys.executable
     tiny = solve_stage_src(alarm=330, length=24, count=64)
 
@@ -267,12 +266,10 @@ def main() -> None:
     # recent recovery still lands an accelerator bench record quickly.
     # The record is published into the SAME log this ladder writes
     # (bench.py's _publish_record honors DEPPY_TPU_REVAL_LOG), which is
-    # the file later bench invocations scan; and bench.py must not arm
-    # a second ladder from inside this one.
+    # the file later bench invocations scan.
     env_bench = dict(env_rest)
     if a.log:
         env_bench["DEPPY_TPU_REVAL_LOG"] = os.path.abspath(a.log)
-    env_bench["DEPPY_BENCH_ARM_LADDER"] = "0"
     # The ladder just probed healthy, so bench.py's worker-restart retry
     # budget (4 probes x 150s) is dead weight here; one probe keeps its
     # worst case (probe + run + re-probe + retry run ≈ 3200s) inside the
@@ -356,7 +353,7 @@ def main() -> None:
             # so record the measured default.  core._resolved_search_impl
             # reads this file for "auto" on this backend; the driver's
             # end-of-round commit carries it, and a human reviews the
-            # row like any other BASELINE.md measurement.  The write is
+            # row like any other chip measurement.  The write is
             # instant, so it lands even if the window dies during E-I —
             # but the REMAINING stages must keep measuring the pre-flip
             # substrate (their artifacts are compared round-over-round
@@ -435,9 +432,9 @@ def main() -> None:
                        [py, os.path.join(ROOT, "scripts", "lane_probe.py"),
                         *i_shape, *log_args],
                        env_rest, 5400, a.log, require_stage_line=False)
-    # ladder-complete is a CONTRACT line (BASELINE.md: "a green
-    # ladder-complete line means every safe measurement actually
-    # landed, and the fused bet has a recorded verdict either way") —
+    # ladder-complete is a CONTRACT line (a green ladder-complete line
+    # means every safe measurement actually landed, and the fused bet
+    # has a recorded verdict either way) —
     # a lane probe that measured nothing (rc!=0: aborted before any
     # step, or backend flip) must not produce it.  lane_probe itself
     # exits 0 when it measured up to a crashed boundary, which IS a

@@ -2,8 +2,7 @@
 
 Captures a ``jax.profiler.trace`` around ONE warm batched solve at the
 headline shape and reduces the raw trace to the numbers the per-trip
-overhead model is built on (BASELINE.md "where the TPU search time
-goes"): total traced wall, device-compute total, and the top-N trace
+overhead model is built on: total traced wall, device-compute total, and the top-N trace
 events by accumulated duration.  The point is to replace the DERIVED
 ~175µs/while-trip model with observed event timings — SURVEY.md §5's
 tracing-equivalence row.

@@ -572,6 +572,27 @@ class TestAutoRouting:
         assert br.state() == "closed"
         monkeypatch.setattr(sat_solver, "_ENGINE_USABLE", None)
 
+    def test_reprobe_needs_a_dispatch_not_a_device_list(self, monkeypatch):
+        """jax.devices() answers from its cache once the backend is up;
+        the breaker closes only on a dispatch that ran."""
+        import jax
+
+        from deppy_tpu.sat import solver as sat_solver
+
+        br = faults.CircuitBreaker(failure_threshold=1, reset_after_s=60)
+        faults.set_default_breaker(br)
+        br.record_failure()
+        assert jax.devices()
+
+        def dead(*a, **k):
+            raise RuntimeError("device lost")
+
+        monkeypatch.setattr(jax, "device_put", dead)
+        monkeypatch.setattr(sat_solver, "_ENGINE_USABLE", None)
+        assert sat_solver.reprobe_engine() is False
+        assert br.state() == "open"
+        monkeypatch.setattr(sat_solver, "_ENGINE_USABLE", None)
+
 
 # ------------------------------------------------------------ service chaos
 

@@ -247,8 +247,6 @@ def test_fused_win_captures_bench_fused(scripted):
     assert names.index("F:tpu-ab") < names.index("F2:bench-fused") < \
         names.index("E:suite")
     assert s.envs["F2:bench-fused"]["DEPPY_TPU_SEARCH"] == "fused"
-    # The F2 bench must publish into the ladder log, like stage D.
-    assert s.envs["F2:bench-fused"]["DEPPY_BENCH_ARM_LADDER"] == "0"
 
 
 def test_fused_loss_skips_bench_fused(scripted):

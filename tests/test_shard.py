@@ -27,7 +27,6 @@ from deppy_tpu.sat.errors import BackendCapabilityError
 jax = pytest.importorskip("jax")
 
 from deppy_tpu.engine import core, driver  # noqa: E402
-from deppy_tpu.parallel import _compat  # noqa: E402
 from deppy_tpu.parallel.mesh import (default_mesh,  # noqa: E402
                                      mesh_devices_from_env, serving_mesh)
 from deppy_tpu.sched import Scheduler  # noqa: E402
@@ -81,32 +80,6 @@ def _assert_results_identical(problems, base, other, ctx=""):
             np.asarray(b.core)[: p.n_cons],
             np.asarray(o.core)[: p.n_cons]), f"{ctx} lane {i}: core"
         assert int(b.steps) == int(o.steps), f"{ctx} lane {i}: steps"
-
-
-# ------------------------------------------------------------- compat shim
-
-
-class TestCompatShim:
-    def test_resolves_installed_shard_map(self):
-        fn = _compat.resolve_shard_map()
-        assert callable(fn)
-        # Whatever the installed spelling, the shim found its check
-        # kwarg (or decided to drop it) without raising.
-        assert _compat._check_param() in ("check_rep", "check_vma", None)
-
-    @pytest.mark.parametrize("kwarg", ["check_rep", "check_vma"])
-    def test_both_spellings_dispatch(self, kwarg):
-        """Old (check_rep) and new (check_vma) call sites both run on
-        the installed JAX — the exact drift class that took out 17
-        tier-1 tests on 0.4.37."""
-        from jax.sharding import PartitionSpec as P
-
-        mesh = default_mesh()
-        fn = _compat.shard_map(
-            lambda x: x * 2, mesh=mesh, in_specs=P("batch"),
-            out_specs=P("batch"), **{kwarg: False})
-        x = np.arange(16, dtype=np.int32)
-        np.testing.assert_array_equal(np.asarray(jax.jit(fn)(x)), x * 2)
 
 
 # -------------------------------------------------------- mesh resolution
