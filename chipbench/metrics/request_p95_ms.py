@@ -1,0 +1,6 @@
+"""95th percentile latency of all requests sent in the window, due time
+to last byte of the answer, in milliseconds."""
+
+
+def read(run):
+    return run.latency_ms(0.95)
