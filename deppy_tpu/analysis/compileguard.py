@@ -264,4 +264,9 @@ def observe(entry: str, fn, *, static=None, budget: Optional[int] = None):
             return out
         return fn(*args, **kwargs)
 
+    if isinstance(fn, functools.partial):
+        # functools.wraps finds no name on a partial, and XLA would name
+        # the program after this wrapper (``jit_traced``): take the
+        # wrapped function's name instead.
+        traced.__name__ = traced.__qualname__ = fn.func.__name__
     return traced

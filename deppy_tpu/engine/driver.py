@@ -783,40 +783,44 @@ def _host_core_rows(problems, idx, d: _Dims, budget, spent,
     from ..sat.host import HostEngine
 
     # The "silent host fallback" made loud: every row routed here counts.
-    telemetry.default_registry().counter(
+    reg = telemetry.default_registry()
+    reg.counter(
         "deppy_host_fallback_rows_total",
         "UNSAT rows whose core extraction routed to the host spec engine.",
     ).inc(len(idx))
     _rep = telemetry.current_report()
     if _rep is not None:
         _rep.host_fallback_rows += len(idx)
-
-    cores = np.zeros((len(idx), d.NCON), bool)
-    steps = np.zeros(len(idx), np.int64)
-    for r, i in enumerate(idx):
-        remaining = int(budget) - int(spent[r])
-        if remaining <= 0:
-            steps[r] = 1  # already over: one tick keeps the lane RUNNING
-            continue
-        spec_steps = 0
-        if allow_device and _spec_core_enabled():
-            mask, spec_steps = _speculative_core_mask(problems[i], remaining)
-            if mask is not None:
-                cores[r, : problems[i].n_cons] = mask
-                steps[r] = spec_steps
+    with reg.span("driver.core_stage", lanes=len(idx), host=True):
+        cores = np.zeros((len(idx), d.NCON), bool)
+        steps = np.zeros(len(idx), np.int64)
+        for r, i in enumerate(idx):
+            remaining = int(budget) - int(spent[r])
+            if remaining <= 0:
+                # Already over: one tick keeps the lane RUNNING.
+                steps[r] = 1
                 continue
-            if spec_steps >= remaining:
+            spec_steps = 0
+            if allow_device and _spec_core_enabled():
+                mask, spec_steps = _speculative_core_mask(problems[i],
+                                                          remaining)
+                if mask is not None:
+                    cores[r, : problems[i].n_cons] = mask
+                    steps[r] = spec_steps
+                    continue
+                if spec_steps >= remaining:
+                    steps[r] = remaining + 1
+                    continue
+            eng = HostEngine(problems[i], max_steps=remaining - spec_steps)
+            try:
+                cores[r, : problems[i].n_cons] = eng.unsat_core_mask()
+                steps[r] = spec_steps + eng.steps
+            except Incomplete:
+                # Budget exhausted mid-sweep: mirror the device contract
+                # — steps past the budget mark the lane Incomplete on
+                # decode.
                 steps[r] = remaining + 1
-                continue
-        eng = HostEngine(problems[i], max_steps=remaining - spec_steps)
-        try:
-            cores[r, : problems[i].n_cons] = eng.unsat_core_mask()
-            steps[r] = spec_steps + eng.steps
-        except Incomplete:
-            # Budget exhausted mid-sweep: mirror the device contract —
-            # steps past the budget mark the lane Incomplete on decode.
-            steps[r] = remaining + 1
-    return cores, steps
+        return cores, steps
 
 
 def _profile_dispatch(t0, problems, d: _Dims, steps: np.ndarray,
@@ -905,17 +909,19 @@ def _solve_monolith(problems, budget, mesh, trace_cap,
                          full=True if not host_core else None)
     if rep is not None:
         rep.add_wall("device_put", sp.dur_s)
-    if _spmd_entry:
-        fn = batched_solve_sharded(mesh, d.V, d.NCON, d.NV, trace_cap,
-                                   with_core=not host_core)
-    else:
-        fn = core.batched_solve(d.V, d.NCON, d.NV, trace_cap,
-                                with_core=not host_core)
-    res = fn(pts, budget)
+    with reg.span("driver.launch", lanes=int(d.B)):
+        if _spmd_entry:
+            fn = batched_solve_sharded(mesh, d.V, d.NCON, d.NV, trace_cap,
+                                       with_core=not host_core)
+        else:
+            fn = core.batched_solve(d.V, d.NCON, d.NV, trace_cap,
+                                    with_core=not host_core)
+        res = fn(pts, budget)
     # One batched fetch for the whole result tree: each individual
     # device→host transfer pays its own round trip, so per-field
     # np.asarray would cost 6 of them.
-    res = jax.device_get(res)
+    with reg.span("driver.fetch", lanes=int(d.B)):
+        res = jax.device_get(res)
     outcome = np.asarray(res.outcome)
     installed = np.asarray(res.installed)
     cores = np.asarray(res.core)
@@ -1040,19 +1046,22 @@ def _solve_split(problems, budget, mesh, trace_cap) -> List[core.SolveResult]:
     if rep is not None:
         rep.add_wall("device_put", sp.dur_s)
 
-    fn_a = core.batched_search(d.V, d.NCON, d.NV, trace_cap)
-    outs = [fn_a(p, budget, e) for p, e in zip(pts_dev, en_dev)]
+    with reg.span("driver.launch", lanes=total, chunks=n_chunks):
+        fn_a = core.batched_search(d.V, d.NCON, d.NV, trace_cap)
+        outs = [fn_a(p, budget, e) for p, e in zip(pts_dev, en_dev)]
 
-    # Phase 2 dispatches immediately on the same device-resident chunks,
-    # gated per lane by the phase-1 result — no host round trip in between.
-    fn_b = core.batched_minimize_gated(d.V, d.NCON, d.NV)
-    res_b = [
-        fn_b(p, o[0], o[2], o[1], budget, o[3], e)
-        for p, o, e in zip(pts_dev, outs, en_dev)
-    ]
+        # Phase 2 dispatches immediately on the same device-resident
+        # chunks, gated per lane by the phase-1 result — no host round
+        # trip in between.
+        fn_b = core.batched_minimize_gated(d.V, d.NCON, d.NV)
+        res_b = [
+            fn_b(p, o[0], o[2], o[1], budget, o[3], e)
+            for p, o, e in zip(pts_dev, outs, en_dev)
+        ]
 
     # One small fetch decides the phase-3 strategy (results + steps only).
-    small = jax.device_get([(o[0], o[3], o[5]) for o in outs])
+    with reg.span("driver.fetch", lanes=total, chunks=n_chunks):
+        small = jax.device_get([(o[0], o[3], o[5]) for o in outs])
     result = np.concatenate([s[0] for s in small])
     steps = np.concatenate([s[1] for s in small]).astype(np.int64)
     trace_n = np.concatenate([s[2] for s in small])
@@ -1073,14 +1082,17 @@ def _solve_split(problems, budget, mesh, trace_cap) -> List[core.SolveResult]:
         # planes; the core phase probes with activations disabled, so its
         # full-space planes are derived here from the resident compact
         # tensors (no host round trip).
-        fn_cg = core.batched_core_gated(d.V, d.NCON, d.NV)
-        red = core.phases_reduced()
-        # Derive per chunk inside the loop so only one chunk's full planes
-        # are live at a time (they free once its dispatch retires).
-        res_c = [
-            fn_cg(_derive_full(p, d) if red else p, o[0], budget, o[3], e)
-            for p, o, e in zip(pts_dev, outs, en_dev)
-        ]
+        with reg.span("driver.launch", lanes=total, chunks=n_chunks):
+            fn_cg = core.batched_core_gated(d.V, d.NCON, d.NV)
+            red = core.phases_reduced()
+            # Derive per chunk inside the loop so only one chunk's full
+            # planes are live at a time (they free once its dispatch
+            # retires).
+            res_c = [
+                fn_cg(_derive_full(p, d) if red else p, o[0], budget,
+                      o[3], e)
+                for p, o, e in zip(pts_dev, outs, en_dev)
+            ]
     elif unsat_idx.size:
         # Few UNSAT lanes: giant problems route to the host spec engine
         # (HOST_CORE_NCONS — kept-member probes are full SAT searches the
@@ -1097,19 +1109,22 @@ def _solve_split(problems, budget, mesh, trace_cap) -> List[core.SolveResult]:
         ]
         b = 0
         if dev_idx.size:
-            fn_c = core.batched_core(d.V, d.NCON, d.NV)
             b = min(_pad_group(dev_idx.size, mesh), CH)
-            for idx in [dev_idx[i: i + b]
-                        for i in range(0, dev_idx.size, b)]:
-                res_c.append(fn_c(
-                    # The core phase reads only the full-space planes: skip
-                    # the reduced build on these re-gathered rows.
+            with reg.span("driver.core_stage", lanes=int(dev_idx.size)):
+                staged = [(
+                    # The core phase reads only the full-space planes:
+                    # skip the reduced build on these re-gathered rows.
                     _put_chunk(_gather_rows(pts_np, idx, b, empty_row),
                                mesh, d, full=True, red=False),
-                    budget,
                     _to_device(_pad_rows(steps[idx], b), mesh),
                     _to_device(np.arange(b) < idx.size, mesh),
-                ))
+                ) for idx in [dev_idx[i: i + b]
+                              for i in range(0, dev_idx.size, b)]]
+            with reg.span("driver.launch", lanes=b * len(staged),
+                          chunks=len(staged)):
+                fn_c = core.batched_core(d.V, d.NCON, d.NV)
+                res_c = [fn_c(pts_c, budget, steps_c, en_c)
+                         for pts_c, steps_c, en_c in staged]
         if host_idx.size:
             # Runs on the host CPU while the device chews on the phase-2/3
             # dispatches above — the final fetch below synchronizes both.
@@ -1128,7 +1143,8 @@ def _solve_split(problems, budget, mesh, trace_cap) -> List[core.SolveResult]:
     fetch = {"b": res_b if sat_any else [], "c": res_c}
     if trace_cap > 0:
         fetch["tr"] = [o[4] for o in outs]
-    fetched = jax.device_get(fetch)
+    with reg.span("driver.fetch", lanes=total, chunks=n_chunks):
+        fetched = jax.device_get(fetch)
 
     if sat_any:
         inst_c = np.concatenate([r[0] for r in fetched["b"]])

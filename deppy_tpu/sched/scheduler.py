@@ -971,37 +971,40 @@ class Scheduler:
         if max_steps is None:
             max_steps = self.max_steps
         budget = int(_budget(max_steps))
-        problems = [encode(vs) for vs in problem_vars]
-        for p in problems:
-            if p.errors:
-                raise InternalSolverError(p.errors)
-        # Capture the request's effective deadline (explicit scope,
-        # enclosing scope, or ambient env) as an OBJECT: its clock keeps
-        # ticking across the thread hop to the dispatch loop.
-        with faults.deadline_scope(deadline_s), faults.ambient_deadline():
-            dl = faults.current_deadline()
-        results: List[object] = [None] * len(problems)
         pending: List[tuple] = []
         warm_pending: List[tuple] = []
-        for i, p in enumerate(problems):
-            key = fingerprint(p)
-            if self.speculate is not None:
-                # ISSUE 14: retain the served family so a later catalog
-                # publish can be applied to it and pre-solved.
-                self.speculate.observe(key, problem_vars[i])
-            hit, plan = self.cache.lookup_or_plan(p, key, budget)
-            if hit is not MISS:
-                results[i] = hit  # bypasses the queue entirely
-            elif plan is not None:
-                # ISSUE 10: a certified warm plan queues in the
-                # incremental size class — warm lanes coalesce with each
-                # other instead of padding out a cold batch.
-                warm_pending.append(
-                    (i, _Lane(p, key, max_steps, budget, dl, warm=plan,
-                              tenant=tenant)))
-            else:
-                pending.append((i, _Lane(p, key, max_steps, budget, dl,
-                                         tenant=tenant)))
+        with telemetry.default_registry().span(
+                "sched.encode", problems=len(problem_vars)):
+            problems = [encode(vs) for vs in problem_vars]
+            for p in problems:
+                if p.errors:
+                    raise InternalSolverError(p.errors)
+            # Capture the request's effective deadline (explicit scope,
+            # enclosing scope, or ambient env) as an OBJECT: its clock
+            # keeps ticking across the thread hop to the dispatch loop.
+            with faults.deadline_scope(deadline_s), \
+                    faults.ambient_deadline():
+                dl = faults.current_deadline()
+            results: List[object] = [None] * len(problems)
+            for i, p in enumerate(problems):
+                key = fingerprint(p)
+                if self.speculate is not None:
+                    # Retain the served family so a later catalog
+                    # publish can be applied to it and pre-solved.
+                    self.speculate.observe(key, problem_vars[i])
+                hit, plan = self.cache.lookup_or_plan(p, key, budget)
+                if hit is not MISS:
+                    results[i] = hit  # bypasses the queue entirely
+                elif plan is not None:
+                    # A certified warm plan queues in the incremental
+                    # size class — warm lanes coalesce with each other
+                    # instead of padding out a cold batch.
+                    warm_pending.append(
+                        (i, _Lane(p, key, max_steps, budget, dl, warm=plan,
+                                  tenant=tenant)))
+                else:
+                    pending.append((i, _Lane(p, key, max_steps, budget,
+                                             dl, tenant=tenant)))
         steps = 0
         deadline_misses = 0
         report = None
@@ -1482,57 +1485,87 @@ class Scheduler:
                     g.event.set()
 
     def _loop_inner(self) -> None:
-        while True:
-            discarded = 0
-            spec_orphans: List[_Group] = []
-            groups: List[_Group] = []
-            reason = None
-            with self._cv:
-                while (not self._queue and not self._spec_queue
-                       and not self._stop):
-                    self._cv.wait()
-                if self._stop and self._spec_queue:
-                    # Shutdown discards the speculative backlog: no
-                    # submitter waits on a pre-solve, and opportunistic
-                    # work must never slow a drain.  Optimize probes
-                    # (ISSUE 18) ride this queue WITH a waiter — their
-                    # groups are failed below, outside the lock.
-                    discarded = self._spec_depth
-                    spec_orphans = self._spec_queue
-                    self._spec_queue = []
-                    self._spec_depth = 0
-                    self._spec_keys.clear()
-                    if self._g_spec_depth is not None:
-                        self._g_spec_depth.set(0)
-                if self._queue:
-                    groups, reason = self._drain_locked(force=self._stop)
-                    if not groups:
-                        # A live flush is pending but not yet due.  The
-                        # speculative queue is NOT consulted in this
-                        # window: a pre-solve dispatch here could push
-                        # the live flush past max_wait — idle priority
-                        # means idle, not "between live flushes".
-                        head_due = (self._head_locked().enq_t
-                                    + self.max_wait_s)
-                        delay = head_due - time.monotonic()
-                        self._cv.wait(timeout=max(delay, 0.001))
-                        continue
-                elif self._spec_queue:
-                    # ISSUE 14: live lanes are empty — drain ONE
-                    # speculative flush.  Live submits arriving during
-                    # the dispatch preempt at the next loop iteration
-                    # (the flush boundary).
-                    groups, reason = self._drain_spec_locked()
-            for g in spec_orphans:
-                if not g.event.is_set():
-                    g.error = RuntimeError(
-                        "scheduler stopped before optimize dispatch")
-                    g.event.set()
-            if discarded and self.speculate is not None:
-                self.speculate.note_discarded(discarded)
-            if not groups:
-                return  # stopped and drained
-            self._dispatch(groups, reason)
+        # The loop's two waits are timeline phases, one span per wait
+        # and not per wake-up: ``sched.idle`` while nothing is queued,
+        # ``sched.coalesce`` while the head is queued but not yet due.
+        # Spans open and close outside the lock; a wait starts only
+        # under the span of its kind, in the same hold of the lock that
+        # found it due, so no notify is lost between the two.
+        reg = telemetry.default_registry()
+        waiting = None
+        try:
+            while True:
+                discarded = 0
+                spec_orphans: List[_Group] = []
+                groups: List[_Group] = []
+                reason = None
+                phase = None
+                with self._cv:
+                    if (not self._queue and not self._spec_queue
+                            and not self._stop):
+                        phase = "sched.idle"
+                        if waiting is not None and waiting.name == phase:
+                            self._cv.wait()
+                            continue
+                    if self._stop and self._spec_queue:
+                        # Shutdown discards the speculative backlog: no
+                        # submitter waits on a pre-solve, and
+                        # opportunistic work must never slow a drain.
+                        # Optimize probes ride this queue WITH a waiter
+                        # — their groups are failed below, outside the
+                        # lock.
+                        discarded = self._spec_depth
+                        spec_orphans = self._spec_queue
+                        self._spec_queue = []
+                        self._spec_depth = 0
+                        self._spec_keys.clear()
+                        if self._g_spec_depth is not None:
+                            self._g_spec_depth.set(0)
+                    if self._queue:
+                        groups, reason = self._drain_locked(
+                            force=self._stop)
+                        if not groups:
+                            # A live flush is pending but not yet due.
+                            # The speculative queue is NOT consulted in
+                            # this window: a pre-solve dispatch here
+                            # could push the live flush past max_wait —
+                            # idle priority means idle, not "between
+                            # live flushes".
+                            phase = "sched.coalesce"
+                            if (waiting is not None
+                                    and waiting.name == phase):
+                                head_due = (self._head_locked().enq_t
+                                            + self.max_wait_s)
+                                delay = head_due - time.monotonic()
+                                self._cv.wait(timeout=max(delay, 0.001))
+                                continue
+                    elif self._spec_queue:
+                        # Live lanes are empty — drain ONE speculative
+                        # flush.  Live submits arriving during the
+                        # dispatch preempt at the next loop iteration
+                        # (the flush boundary).
+                        groups, reason = self._drain_spec_locked()
+                if waiting is not None:
+                    waiting.__exit__(None, None, None)
+                    waiting = None
+                if phase is not None:
+                    # A wait of another kind begins: the next turn
+                    # waits under its span.
+                    waiting = reg.span(phase).__enter__()
+                    continue
+                for g in spec_orphans:
+                    if not g.event.is_set():
+                        g.error = RuntimeError(
+                            "scheduler stopped before optimize dispatch")
+                        g.event.set()
+                if discarded and self.speculate is not None:
+                    self.speculate.note_discarded(discarded)
+                if not groups:
+                    return  # stopped and drained
+                self._dispatch(groups, reason)
+        finally:
+            if waiting is not None:
+                waiting.__exit__(None, None, None)
 
     def _drain_spec_locked(self):
         """Pick one speculative flush (caller holds the lock): the
@@ -1656,8 +1689,9 @@ class Scheduler:
                                     link.get("span_id"))
                     faults.inject("sched.dispatch")
                     report = self._solve_lanes(lanes, timing)
-            for lane in lanes:
-                self._maybe_cache(lane)
+            with reg.span("sched.deliver", lanes=len(lanes)):
+                for lane in lanes:
+                    self._maybe_cache(lane)
         except BaseException as e:  # noqa: BLE001 — re-raised per request
             for g in groups:
                 g.error = e
@@ -1673,26 +1707,29 @@ class Scheduler:
                               if g.speculative))
         finally:
             dur = time.monotonic() - t0
-            # Read-modify-write under the CV: admission_retry_after
-            # reads the EWMA from handler threads while the dispatch
-            # loop updates it here (the first real finding the
-            # concurrency audit fixed; pinned by
-            # tests/test_analysis.py::TestSchedulerEwmaRegression).
-            with self._cv:
-                self._dispatch_ewma_s = (0.8 * self._dispatch_ewma_s
-                                         + 0.2 * dur)
+            # The wake-ups are the rest of sched.deliver.
+            with telemetry.default_registry().span("sched.deliver",
+                                                   groups=len(groups)):
+                # Read-modify-write under the CV: admission_retry_after
+                # reads the EWMA from handler threads while the dispatch
+                # loop updates it here (the first real finding the
+                # concurrency audit fixed; pinned by
+                # tests/test_analysis.py::TestSchedulerEwmaRegression).
+                with self._cv:
+                    self._dispatch_ewma_s = (0.8 * self._dispatch_ewma_s
+                                             + 0.2 * dur)
+                    for g in groups:
+                        if g.speculative:
+                            # The pre-solve is stored (or failed) —
+                            # later duplicates dedupe through the cache
+                            # peek, not the in-flight key set.
+                            self._spec_keys.difference_update(
+                                lane.key for lane in g.lanes)
+                timing["dispatch_s"] = dur
                 for g in groups:
-                    if g.speculative:
-                        # The pre-solve is stored (or failed) — later
-                        # duplicates dedupe through the cache peek, not
-                        # the in-flight key set.
-                        self._spec_keys.difference_update(
-                            lane.key for lane in g.lanes)
-            timing["dispatch_s"] = dur
-            for g in groups:
-                g.timing.update(timing)
-                g.report = report
-                g.event.set()
+                    g.timing.update(timing)
+                    g.report = report
+                    g.event.set()
 
     def _maybe_cache(self, lane: _Lane) -> None:
         r = lane.result

@@ -559,8 +559,10 @@ class Server:
                 "error": msg,
                 "retry_after_s": round(retry_after, 3),
             }
+        reg = telemetry.default_registry()
         try:
-            problems = problem_io.problems_from_document(doc)
+            with reg.span("service.parse"):
+                problems = problem_io.problems_from_document(doc)
         except problem_io.ProblemFormatError as e:
             self.metrics.observe_error()
             return 400, {"error": str(e)}
@@ -606,10 +608,11 @@ class Server:
 
         outcomes = {"sat": 0, "unsat": 0, "incomplete": 0}
         rendered = []
-        for res in results:
-            r = problem_io.result_to_dict(res)
-            outcomes[r["status"]] += 1
-            rendered.append(r)
+        with reg.span("service.render", problems=len(results)):
+            for res in results:
+                r = problem_io.result_to_dict(res)
+                outcomes[r["status"]] += 1
+                rendered.append(r)
         if (request_stats is not None
                 and "deadline_misses" not in request_stats
                 and deadline_s is not None):
@@ -1503,7 +1506,8 @@ def _api_handler(server: Server):
             # A client-controlled Content-Length must not be able to
             # buffer unbounded memory on the service (enforced inside
             # the shared body reader).
-            doc, err = self._read_json_body()
+            with telemetry.default_registry().span("service.parse"):
+                doc, err = self._read_json_body()
             if err is not None:
                 return err
             try:
@@ -1523,7 +1527,8 @@ def _api_handler(server: Server):
                 resp = dict(resp)
                 resp["timings"] = {k: round(float(v), 6)
                                    for k, v in sorted(timings.items())}
-            return self._send_json(status, resp)
+            with telemetry.default_registry().span("service.render"):
+                return self._send_json(status, resp)
 
     return Handler
 

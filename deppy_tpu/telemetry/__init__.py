@@ -18,6 +18,7 @@ from .registry import (
     RATIO_BUCKETS,
     SECONDS_BUCKETS,
     STAGE_BUCKETS,
+    TIMELINE,
     Counter,
     Gauge,
     Histogram,
@@ -51,6 +52,7 @@ __all__ = [
     "RATIO_BUCKETS",
     "SECONDS_BUCKETS",
     "STAGE_BUCKETS",
+    "TIMELINE",
     "begin_report",
     "configure_sink",
     "current_report",

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -49,6 +50,36 @@ STAGE_BUCKETS = (0.0, 1.0, 2.0, 3.0)
 # up to the widest probed dispatch width (scripts/lane_probe.py).
 LANE_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0,
                 512.0, 1024.0, 2048.0, 4096.0)
+
+# Spans that are phases of the profiler's timeline: while a JAX profiler
+# session records, each also opens a ``jax.profiler.TraceAnnotation`` of
+# its name, so a device trace says what the host was doing in each of
+# the device's idle gaps.  Only leaf phases, which never nest inside one
+# another on a thread, belong here: a gap takes the name of the host
+# event that overlaps it most, and an enclosing span (``service.request``,
+# ``sched.dispatch``, ``driver.solve``) would win every gap.  The
+# dispatch loop's phases tile its thread; the handler threads' phases
+# are the front end's self time (docs/observability.md, "Profiler
+# timeline").
+TIMELINE = frozenset({
+    "sched.idle", "sched.coalesce", "sched.deliver",
+    "driver.pad_pack", "driver.device_put", "driver.launch",
+    "driver.fetch", "driver.core_stage", "driver.decode",
+    "service.parse", "sched.encode", "service.render",
+})
+
+
+def _timeline_mark(name: str):
+    """An entered TraceAnnotation named ``name`` while a profiler session
+    records, else None.  JAX is never imported from here: a process
+    without it has no session to record into."""
+    annotation = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                         None)
+    if annotation is None or not annotation.is_enabled():
+        return None
+    mark = annotation(name)
+    mark.__enter__()
+    return mark
 
 
 def iter_sink_events(path: str):
@@ -295,10 +326,14 @@ class Span:
     thread's span stack) and its completed event joins the request's
     trace; without one, behavior — and the emitted event — is
     byte-identical to the pre-trace schema.
+
+    A span named in :data:`TIMELINE` is also a profiler annotation of
+    the same name, open from entry to exit, while a JAX profiler
+    session records.
     """
 
     __slots__ = ("name", "attrs", "_registry", "_t0", "dur_s",
-                 "trace_id", "span_id", "parent_id", "links")
+                 "trace_id", "span_id", "parent_id", "links", "_mark")
 
     def __init__(self, registry: "Registry", name: str, attrs: dict):
         self.name = name
@@ -310,6 +345,7 @@ class Span:
         self.span_id: Optional[str] = None
         self.parent_id: Optional[str] = None
         self.links: Optional[List[dict]] = None
+        self._mark = None
 
     def __setitem__(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -331,6 +367,8 @@ class Span:
     def __enter__(self) -> "Span":
         from . import trace as _trace
 
+        if self.name in TIMELINE:
+            self._mark = _timeline_mark(self.name)
         self._t0 = time.perf_counter()
         _trace.enter_span(self)
         return self
@@ -339,6 +377,9 @@ class Span:
         from . import trace as _trace
 
         self.dur_s = time.perf_counter() - self._t0
+        if self._mark is not None:
+            self._mark.__exit__(None, None, None)
+            self._mark = None
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         _trace.exit_span(self)

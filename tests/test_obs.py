@@ -576,13 +576,15 @@ def _trace_skeleton(doc: dict):
     """Span-name tree from `deppy trace --output json`, with dispatch
     traces grafted under their link targets exactly as the text
     renderer does.  Timings and ids are run-dependent; the NAME
-    structure is the pinned surface."""
+    structure is the pinned surface.  Siblings keep the CLI's order:
+    by ``ts`` (milliseconds), then in the order they were recorded —
+    adjacent spans that close in the same millisecond keep their real
+    order instead of an alphabetical one."""
     spans = doc["spans"]
     by_id = {sp["span_id"]: sp for sp in spans}
     children: dict = {}
     roots = []
-    for sp in sorted(spans, key=lambda s: (s.get("ts", 0.0),
-                                           s.get("name", ""))):
+    for sp in sorted(spans, key=lambda s: s.get("ts", 0.0)):
         parent = sp.get("parent_id")
         if parent not in by_id and sp.get("links"):
             parent = sp["links"][0].get("span_id")
@@ -592,10 +594,7 @@ def _trace_skeleton(doc: dict):
             roots.append(sp)
 
     def _tree(sp):
-        kids = tuple(_tree(c) for c in
-                     sorted(children.get(sp["span_id"], []),
-                            key=lambda s: (s.get("ts", 0.0),
-                                           s.get("name", ""))))
+        kids = tuple(_tree(c) for c in children.get(sp["span_id"], []))
         return (sp["name"], kids)
 
     return [_tree(sp) for sp in roots]
