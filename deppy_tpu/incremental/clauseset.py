@@ -90,21 +90,68 @@ def problem_rows(problem: Problem) -> "Counter[tuple]":
         return memo
     n = problem.n_vars
     rows: "Counter[tuple]" = Counter()
-    c = problem.clauses
     per_subject: Dict[int, int] = {}
-    if c.size:
-        kept = np.where(np.abs(c) <= n, c, 0)
-        for row in kept:
-            lits = tuple(row[row != 0].tolist())
-            subj = abs(lits[0]) - 1 if lits else -1
-            ordinal = per_subject.get(subj, 0)
-            per_subject[subj] = ordinal + 1
-            rows[("c", ordinal) + lits] += 1
-    for ids_row, bound in zip(problem.card_ids, problem.card_n):
-        members = ids_row[ids_row >= 0]
-        rows[("k", int(bound)) + tuple(sorted(members.tolist()))] += 1
+    # One ``tolist()`` walk each: per-row numpy slicing costs more than
+    # the row it builds.
+    c = problem.clauses
+    for row in (c.tolist() if c.size else ()):
+        lits = tuple([lit for lit in row if lit and -n <= lit <= n])
+        subj = abs(lits[0]) - 1 if lits else -1
+        ordinal = per_subject.get(subj, 0)
+        per_subject[subj] = ordinal + 1
+        rows[("c", ordinal) + lits] += 1
+    for ids_row, bound in zip(problem.card_ids.tolist(),
+                              problem.card_n.tolist()):
+        rows[("k", int(bound))
+             + tuple(sorted([m for m in ids_row if m >= 0]))] += 1
     problem.__dict__["_inc_rows"] = rows
     return rows
+
+
+# ``_multiset_as_set``'s form: distinct rows, copies past the first, size.
+RowSet = Tuple[frozenset, Dict[tuple, int], int]
+
+
+def _multiset_as_set(rows: "Counter[tuple]") -> RowSet:
+    """The exact set form of a row multiset: ``(keys, extra, size)``,
+    its distinct rows as a frozenset, each repeated row's copies past
+    the first, and the multiset's size.  Set intersection and difference
+    reuse the hashes a frozenset holds, where ``Counter &`` and
+    ``Counter -`` rehash every row tuple; the form holds the Counter's
+    own row tuples, and no count makes it any larger."""
+    return (frozenset(rows),
+            {row: c - 1 for row, c in rows.items() if c > 1},
+            sum(rows.values()))
+
+
+def problem_rowset(problem: Problem) -> RowSet:
+    """:func:`_multiset_as_set` of :func:`problem_rows`, memoized on the
+    problem like the rows."""
+    memo = problem.__dict__.get("_inc_rowset")
+    if memo is None:
+        memo = _multiset_as_set(problem_rows(problem))
+        problem.__dict__["_inc_rowset"] = memo
+    return memo
+
+
+def _shared(mine: RowSet, theirs: RowSet) -> int:
+    """The size of two set forms' multiset intersection."""
+    shared = len(mine[0] & theirs[0])
+    a, b = mine[1], theirs[1]
+    if a and b:
+        if len(b) < len(a):
+            a, b = b, a
+        shared += sum(min(c, b.get(row, 0)) for row, c in a.items())
+    return shared
+
+
+def _excess(mine: RowSet, theirs: RowSet) -> set:
+    """The rows ``mine`` holds more copies of than ``theirs``: the keys
+    of ``Counter(mine) - Counter(theirs)``."""
+    out = set(mine[0] - theirs[0])
+    extra = theirs[1]
+    out.update(row for row, c in mine[1].items() if c > extra.get(row, 0))
+    return out
 
 
 def vocab_key(problem: Problem) -> Tuple[int, tuple]:
@@ -175,8 +222,8 @@ def touched_cone(problem: Problem, seed_vars, extra_rows) -> np.ndarray:
 
 
 class _Entry:
-    __slots__ = ("key", "_rows", "vocab", "model", "steps", "backtracks",
-                 "_problem")
+    __slots__ = ("key", "_rows", "_rowset", "vocab", "model", "steps",
+                 "backtracks", "_problem")
 
     def __init__(self, key: str, rows: "Optional[Counter[tuple]]", vocab,
                  model: np.ndarray, steps: int, backtracks: int,
@@ -188,6 +235,9 @@ class _Entry:
         # generic-classifier fallback and the snapshot export do, so
         # eager O(problem) hashing there is latency for nothing).
         self._rows = rows
+        # A problem that went through ``plan`` lends its set form.
+        self._rowset = (problem.__dict__.get("_inc_rowset")
+                        if problem is not None else None)
         self._problem = problem if rows is None else None
         self.vocab = vocab
         self.model = model            # bool[n_vars], the final installed set
@@ -208,6 +258,18 @@ class _Entry:
             self._rows = rows
             self._problem = None
         return rows
+
+    @property
+    def rowset(self) -> RowSet:
+        """The set form of :attr:`rows` (:func:`_multiset_as_set`),
+        built on first use like the rows."""
+        rowset = self._rowset
+        if rowset is None:
+            prob = self._problem
+            rowset = (problem_rowset(prob) if prob is not None
+                      else _multiset_as_set(self.rows))
+            self._rowset = rowset
+        return rowset
 
 
 class WarmPlan:
@@ -270,6 +332,13 @@ class ClauseSetIndex:
             "Touched-cone size as a fraction of problem variables, per "
             "planned warm start.",
             buckets=telemetry.RATIO_BUCKETS)
+        self._c_rejects = reg.counter(
+            "deppy_incremental_plan_rejects_total",
+            "Classified deltas that planned no warm start, by reason "
+            "(seed_bound: the changed rows' own variables already pass "
+            "the cone cutoff; cone: the closed cone does; budget: the "
+            "step budget is below the warm floor).",
+            labelname="reason")
         self._n_lookups = 0
         self._n_hits = 0
 
@@ -352,6 +421,8 @@ class ClauseSetIndex:
             raise ValueError(
                 f"model length {model.size} does not match the entry "
                 f"vocabulary ({vocab[0]} variables)")
+        if any(c < 1 for c in rows.values()):
+            raise ValueError("a row count below 1 is no multiset count")
         with self._lock:
             if key in self._entries:
                 return False
@@ -410,15 +481,16 @@ class ClauseSetIndex:
             if account:
                 self._c_delta.inc(label="none")
             return None
-        rows = problem_rows(problem)
+        mine = problem_rowset(problem)
         with self._lock:
-            entry = self._nearest_locked(vocab, rows)
+            entry = self._nearest_locked(vocab, mine)
         if entry is None:
             if account:
                 self._c_delta.inc(label="none")
             return None
-        added = rows - entry.rows
-        removed = entry.rows - rows
+        theirs = entry.rowset
+        added = _excess(mine, theirs)
+        removed = _excess(theirs, mine)
         if not added and not removed:
             klass = DELTA_IDENTICAL
         elif not removed:
@@ -429,39 +501,50 @@ class ClauseSetIndex:
             klass = DELTA_MIXED
         if account:
             self._c_delta.inc(label=klass)
-        seed: List[int] = []
-        for k in list(added) + list(removed):
-            seed.extend(_row_vars(k))
-        cone = touched_cone(problem, seed, removed.keys())
-        fraction = float(cone.sum()) / max(problem.n_vars, 1)
+        n = problem.n_vars
+        # The cone contains its in-range seed, so a seed already past
+        # the cutoff rejects exactly as the closed cone would.
+        seed: set = set()
+        for k in added | removed:
+            seed.update(v for v in _row_vars(k) if 0 <= v < n)
+            if float(len(seed)) / max(n, 1) > self.max_delta_ratio:
+                return self._reject("seed_bound", account)
+        cone = touched_cone(problem, seed, removed)
+        fraction = float(cone.sum()) / max(n, 1)
         if fraction > self.max_delta_ratio:
-            return None
+            return self._reject("cone", account)
         if int(budget) < max(MIN_WARM_BUDGET,
                              WARM_BUDGET_FACTOR * (entry.steps + 1)):
-            return None
+            return self._reject("budget", account)
         warm_assign = np.where(entry.model, 1, -1).astype(np.int8)
         if account:
             self._h_cone.observe(fraction)
         return WarmPlan(problem, key, warm_assign, cone, klass, fraction,
                         entry.key, entry.steps)
 
-    def _nearest_locked(self, vocab, rows) -> Optional[_Entry]:
+    def _reject(self, reason: str, account: bool) -> None:
+        if account:
+            self._c_rejects.inc(label=reason)
+        return None
+
+    def _nearest_locked(self, vocab, mine: RowSet) -> Optional[_Entry]:
         bucket = self._by_vocab.get(vocab)
         if not bucket:
             return None
         best = None
         best_delta = None
-        n_rows = sum(rows.values())
         # Most recent entries first (churn clusters in time); nearest =
         # SMALLEST symmetric difference, not largest intersection — two
         # ancestors can share equally many rows while one carries extra
-        # baggage that would all land in the cone.
+        # baggage that would all land in the cone.  The set forms make
+        # each candidate one exact multiset intersection.
         for k in list(reversed(bucket))[:SCAN_CAP]:
             entry = self._entries.get(k)
             if entry is None:
                 continue
-            shared = sum((rows & entry.rows).values())
-            delta = (n_rows - shared) + (sum(entry.rows.values()) - shared)
+            theirs = entry.rowset
+            shared = _shared(mine, theirs)
+            delta = (mine[2] - shared) + (theirs[2] - shared)
             if best_delta is None or delta < best_delta:
                 best, best_delta = entry, delta
             if best_delta <= ACCEPT_DELTA:

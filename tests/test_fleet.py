@@ -655,6 +655,45 @@ class TestFleetEndToEnd:
         finally:
             srv.shutdown()
 
+    @pytest.mark.parametrize("count,status", [
+        (0, 400), (-3, 400), (10 ** 9, 200)])
+    def test_warmstate_import_row_counts(self, count, status):
+        """A row count below 1 is refused with nothing admitted.  A huge
+        one costs what a count of 1 does: the index's set form holds the
+        distinct rows, never one object per copy."""
+        from deppy_tpu import io as problem_io
+        from deppy_tpu.fleet.snapshot import _seal
+        from deppy_tpu.sat.encode import encode
+        from deppy_tpu.sched.cache import fingerprint
+
+        srv = _host_server()
+        try:
+            _request(srv.api_port, "POST", "/v1/resolve",
+                     _family_doc("count"))
+            _, body, _ = _request(srv.api_port, "GET", "/debug/warmstate")
+            raw = dict(json.loads(body)["index"][0], key="forged")
+            raw["rows"][0][1] = count
+            index = srv.scheduler.incremental
+            before = len(index)
+            t0 = time.monotonic()
+            s, body, _ = _request(srv.api_port, "POST", "/debug/warmstate",
+                                  _seal([raw], []))
+            assert s == status
+            if status == 400:
+                assert "row count" in json.loads(body)["error"]
+                assert len(index) == before
+                return
+            assert len(index) == before + 1
+            keys, extra, size = index._entries["forged"].rowset
+            assert len(keys) == len(raw["rows"])
+            assert size == count + len(raw["rows"]) - 1
+            nxt = encode(problem_io.problems_from_document(
+                _family_doc("count", state=2))[0])
+            assert index.plan(nxt, fingerprint(nxt), 1 << 24) is not None
+            assert time.monotonic() - t0 < 10.0
+        finally:
+            srv.shutdown()
+
 
 # --------------------------------------- elastic membership (ISSUE 17)
 

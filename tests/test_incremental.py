@@ -24,6 +24,7 @@ The acceptance surface:
 from __future__ import annotations
 
 import random
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -35,9 +36,12 @@ from deppy_tpu.incremental import (
     DELTA_MIXED,
     DELTA_RETRACTIVE,
     ClauseSetIndex,
+    clauseset,
     problem_rows,
     touched_cone,
+    vocab_key,
 )
+from deppy_tpu.models import random_instance
 from deppy_tpu.sat.encode import encode
 from deppy_tpu.sat.errors import Incomplete, NotSatisfiable
 from deppy_tpu.sat.host import HostEngine, WarmStartConflict
@@ -220,6 +224,227 @@ class TestClauseSetIndex:
             index.store(fingerprint(p), p, np.zeros(p.n_vars, bool),
                         5, 0)
         assert len(index) == 2
+
+
+# ------------------------------------- classifier equivalence (oracle)
+
+
+def legacy_rows(problem):
+    """The row multiset as the per-row numpy classifier built it."""
+    n = problem.n_vars
+    rows = Counter()
+    c = problem.clauses
+    per_subject = {}
+    if c.size:
+        kept = np.where(np.abs(c) <= n, c, 0)
+        for row in kept:
+            lits = tuple(row[row != 0].tolist())
+            subj = abs(lits[0]) - 1 if lits else -1
+            ordinal = per_subject.get(subj, 0)
+            per_subject[subj] = ordinal + 1
+            rows[("c", ordinal) + lits] += 1
+    for ids_row, bound in zip(problem.card_ids, problem.card_n):
+        members = ids_row[ids_row >= 0]
+        rows[("k", int(bound)) + tuple(sorted(members.tolist()))] += 1
+    return rows
+
+
+def legacy_plan(index, problem, budget):
+    """``(delta label, plan fields or None)`` of the Counter-based
+    classifier: ``Counter &`` nearest scan over the bucket's most recent
+    SCAN_CAP entries, then the cone closure before the fraction check."""
+    bucket = index._by_vocab.get(vocab_key(problem))
+    if not bucket:
+        return "none", None
+    rows = legacy_rows(problem)
+    best = best_delta = None
+    n_rows = sum(rows.values())
+    for k in list(reversed(bucket))[:clauseset.SCAN_CAP]:
+        entry = index._entries.get(k)
+        if entry is None:
+            continue
+        shared = sum((rows & entry.rows).values())
+        delta = (n_rows - shared) + (sum(entry.rows.values()) - shared)
+        if best_delta is None or delta < best_delta:
+            best, best_delta = entry, delta
+        if best_delta <= clauseset.ACCEPT_DELTA:
+            break
+    added = rows - best.rows
+    removed = best.rows - rows
+    if not added and not removed:
+        klass = DELTA_IDENTICAL
+    elif not removed:
+        klass = DELTA_ADDITIVE
+    elif not added:
+        klass = DELTA_RETRACTIVE
+    else:
+        klass = DELTA_MIXED
+    seed = []
+    for k in list(added) + list(removed):
+        seed.extend(clauseset._row_vars(k))
+    cone = touched_cone(problem, seed, removed.keys())
+    fraction = float(cone.sum()) / max(problem.n_vars, 1)
+    if fraction > index.max_delta_ratio:
+        return klass, None
+    if int(budget) < max(clauseset.MIN_WARM_BUDGET,
+                         clauseset.WARM_BUDGET_FACTOR * (best.steps + 1)):
+        return klass, None
+    return klass, (klass, cone.tolist(), fraction, best.key, best.steps)
+
+
+def assert_plans_match_legacy(index, problems, budget=1 << 24):
+    """Every problem plans exactly as the oracle: the same None or the
+    same class, cone, fraction, entry and steps, and the same delta
+    counter increment."""
+    for p in problems:
+        assert problem_rows(p) == legacy_rows(p)
+        label, want = legacy_plan(index, p, budget)
+        before = index._c_delta.value
+        plan = index.plan(p, fingerprint(p), budget)
+        got = None if plan is None else (
+            plan.klass, plan.cone.tolist(), plan.cone_fraction,
+            plan.entry_key, plan.entry_steps)
+        assert got == want
+        after = index._c_delta.value
+        assert after.get(label, 0) == before.get(label, 0) + 1
+        assert sum(after.values()) == sum(before.values()) + 1
+
+
+def bench_problems(seeds):
+    """``deppy-sat-bench``-style states: 256 variables named ``"0"`` to
+    ``"255"``, so every state shares one vocabulary."""
+    return [encode(random_instance(256, seed=s)) for s in seeds]
+
+
+def full_index(problems, capacity, **kw):
+    index = ClauseSetIndex(capacity=capacity, registry=telemetry.Registry(),
+                           **kw)
+    rng = random.Random(len(problems))
+    for p in problems:
+        index.store(fingerprint(p), p, np.zeros(p.n_vars, bool),
+                    rng.randrange(1, 5000), 0)
+    return index
+
+
+def dup_atmost_catalog(copies):
+    """A catalog whose one cardinality row appears ``copies`` times:
+    a row multiset count above 1."""
+    vs = bundle_catalog(n_bundles=2)
+    x = vs[0]
+    vs[0] = sat.variable(x.identifier, *(
+        list(x.constraints) + [sat.at_most(1, "b0v4", "b0v5")] * copies))
+    return vs
+
+
+CHURN_CASES = {
+    "identical": (None, [None]),
+    "additive": (None, [("add-conflict", 2), ("add-dep", 4)]),
+    "retractive": (("add-conflict", 2), [None]),
+    "mixed": (None, [("flip-dep", 2), ("drop-dep", 5)]),
+    "cardinality": (None, [("add-atmost", 1), ("add-mandatory", 3)]),
+}
+
+
+class TestClassifierEquivalence:
+    @pytest.mark.parametrize("seed", range(4))
+    def test_set_form_is_the_multiset(self, seed):
+        rng = random.Random(seed)
+
+        def draw():
+            return Counter({("k", 1, r): rng.randrange(1, 4)
+                            for r in rng.sample(range(12), 8)})
+
+        a, b = draw(), draw()
+        sa = clauseset._multiset_as_set(a)
+        sb = clauseset._multiset_as_set(b)
+        assert sa[0] == frozenset(a) and sa[2] == sum(a.values())
+        assert clauseset._shared(sa, sb) == sum((a & b).values())
+        assert clauseset._excess(sa, sb) == set(a - b)
+        assert clauseset._excess(sb, sa) == set(b - a)
+
+    @pytest.mark.parametrize("ratio", [0.25, 1.0])
+    def test_random_states_against_full_index(self, ratio):
+        stored = bench_problems(range(1000, 1048))
+        index = full_index(stored, capacity=40, max_delta_ratio=ratio)
+        assert len(index) == 40
+        fresh = bench_problems(range(2000, 2012))
+        # A stored state again is the identical class at delta 0.
+        assert_plans_match_legacy(index, fresh + stored[-3:])
+
+    @pytest.mark.parametrize("case", sorted(CHURN_CASES))
+    @pytest.mark.parametrize("ratio", [0.1, 0.25, 1.0])
+    def test_churn_catalogs(self, case, ratio):
+        base_tweak, new_tweaks = CHURN_CASES[case]
+        base = encode(bundle_catalog(tweak=base_tweak))
+        (_, idx), eng = solve_cold(base)
+        index = indexed(base, eng, idx, max_delta_ratio=ratio)
+        news = [encode(bundle_catalog(tweak=t)) for t in new_tweaks]
+        assert_plans_match_legacy(index, news)
+        assert_plans_match_legacy(index, news, budget=64)
+
+    def test_tie_between_equally_near_entries(self):
+        index = ClauseSetIndex(registry=telemetry.Registry(),
+                               max_delta_ratio=1.0)
+        for b in (1, 3):
+            p = encode(bundle_catalog(tweak=("flip-dep", b)))
+            index.store(fingerprint(p), p, np.zeros(p.n_vars, bool),
+                        10 + b, 0)
+        new = encode(bundle_catalog())
+        assert_plans_match_legacy(index, [new])
+        plan = index.plan(new, fingerprint(new), 1 << 24)
+        # Both sit two rows away; the more recent store wins the tie.
+        assert plan.entry_steps == 13
+
+    @pytest.mark.parametrize("copies", [1, 2, 3])
+    def test_duplicate_rows(self, copies):
+        base = encode(dup_atmost_catalog(2))
+        assert max(problem_rows(base).values()) == 2
+        (_, idx), eng = solve_cold(base)
+        index = indexed(base, eng, idx, max_delta_ratio=1.0)
+        assert_plans_match_legacy(index, [encode(dup_atmost_catalog(copies))])
+
+
+class TestPlanRejects:
+    def test_random_traffic_rejects_before_the_cone(self, monkeypatch):
+        index = full_index(bench_problems(range(1000, 1040)), capacity=32)
+        fresh = bench_problems(range(3000, 3004))
+
+        def boom(*a, **k):
+            raise AssertionError("touched_cone ran")
+
+        monkeypatch.setattr(clauseset, "touched_cone", boom)
+        for p in fresh:
+            assert index.plan(p, fingerprint(p), 1 << 24) is None
+        assert index._c_rejects.value == {"seed_bound": len(fresh)}
+
+    def test_near_duplicate_plans_without_reject(self):
+        base = encode(bundle_catalog())
+        (_, idx), eng = solve_cold(base)
+        index = indexed(base, eng, idx)
+        new = encode(bundle_catalog(tweak=("add-conflict", 2)))
+        assert index.plan(new, fingerprint(new), 1 << 24) is not None
+        assert sum(index._c_rejects.value.values()) == 0
+
+    @pytest.mark.parametrize("ratio,budget,reason", [
+        (0.1, 1 << 24, "cone"),
+        (0.25, 64, "budget"),
+    ])
+    def test_reject_reasons(self, ratio, budget, reason):
+        base = encode(bundle_catalog())
+        (_, idx), eng = solve_cold(base)
+        index = indexed(base, eng, idx, max_delta_ratio=ratio)
+        # add-dep's seed is three of 36 variables; its cone is the
+        # bundle's six.
+        new = encode(bundle_catalog(tweak=("add-dep", 1)))
+        assert index.plan(new, fingerprint(new), budget) is None
+        assert index._c_rejects.value == {reason: 1}
+
+    def test_preview_counts_no_reject(self):
+        index = full_index(bench_problems(range(1000, 1040)), capacity=32)
+        (p,) = bench_problems([3000])
+        assert index.plan(p, fingerprint(p), 1 << 24, account=False) \
+            is None
+        assert index._c_rejects.value == {}
 
 
 # ------------------------------------------------------- warm identity
